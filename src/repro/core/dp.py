@@ -69,14 +69,16 @@ def clip_by_global_norm(tree, clip: float):
 
 def add_gaussian_noise(tree, sigma: float, key):
     leaves, treedef = jax.tree.flatten(tree)
-    keys = jax.random.split(key, len(leaves))
-    noised = [
-        (x + sigma * jax.random.normal(k, x.shape, jnp.float32).astype(jnp.float32)).astype(
-            x.dtype
-        )
-        for x, k in zip(leaves, keys)
-    ]
-    return jax.tree.unflatten(treedef, noised)
+    with jax.named_scope("dp_noise"):
+        keys = jax.random.split(key, len(leaves))
+
+    def noised(x, k):
+        with jax.named_scope("dp_noise"):
+            noise = jax.random.normal(k, x.shape, jnp.float32)
+        return (x + sigma * noise.astype(jnp.float32)).astype(x.dtype)
+
+    return jax.tree.unflatten(
+        treedef, [noised(x, k) for x, k in zip(leaves, keys)])
 
 
 def privatize_update(tree, key, *, mode: str, clip: float, sigma: float,

@@ -249,16 +249,17 @@ def make_parallel_round(loss_fn: Callable, fl: FLConfig, n_clients: int,
                    update_gate=None) -> Tuple[RoundState, RoundMetrics]:
         pr = default_params if params is None else params
         server = make_server_optimizer(fl.server_opt, pr.server_lr)
-        rng, k_avail, k_sel, k_fail, k_dp = jax.random.split(state.rng, 5)
 
-        # ---- GetAvailableClients (Alg.1 line 3) ----
-        avail = jax.random.bernoulli(k_avail, pr.avail_prob,
-                                     (n_clients,)).astype(jnp.float32)
-
-        # ---- ComputeUtility + SelectTopK (line 4) ----
-        # jax.named_scope markers are metadata-only (profiler/HLO names);
-        # they never change the lowered math (docs/DESIGN.md §8)
+        # Every op of the round sits under one phase scope, the same names
+        # as the cohort plan's.  jax.named_scope markers are metadata-only
+        # (profiler/HLO names); they never change the lowered math
+        # (docs/DESIGN.md §8)
         with jax.named_scope("selection"):
+            rng, k_avail, k_sel, k_fail, k_dp = jax.random.split(state.rng, 5)
+            # ---- GetAvailableClients (Alg.1 line 3) ----
+            avail = jax.random.bernoulli(k_avail, pr.avail_prob,
+                                         (n_clients,)).astype(jnp.float32)
+            # ---- ComputeUtility + SelectTopK (line 4) ----
             utility = sel_lib.compute_utility(state.util, fl,
                                               fault_w=pr.fault_util_w)
             k_eff = (state.kctl.k if fl.adaptive_k
@@ -272,11 +273,12 @@ def make_parallel_round(loss_fn: Callable, fl: FLConfig, n_clients: int,
         # fault_process code picks iid/markov/weibull/straggler lanes
         # branch-free; the iid lane reproduces the historical draw bitwise
         local_steps = jax.tree.leaves(batches)[0].shape[1]
-        fail_at, slow, new_fault = fault_proc.fault_step(
-            state.fault, k_fail, pr, n_clients, local_steps)
-        eff_steps, failed = _effective_steps(
-            fail_at, local_steps, ckpt_every_steps, fl.fault_tolerance
-        )
+        with jax.named_scope("fault"):
+            fail_at, slow, new_fault = fault_proc.fault_step(
+                state.fault, k_fail, pr, n_clients, local_steps)
+            eff_steps, failed = _effective_steps(
+                fail_at, local_steps, ckpt_every_steps, fl.fault_tolerance
+            )
 
         # ---- local training, in parallel over clients (line 5) ----
         with jax.named_scope("local_train"):
@@ -302,55 +304,61 @@ def make_parallel_round(loss_fn: Callable, fl: FLConfig, n_clients: int,
             else:
                 norms = jax.vmap(dp_lib.global_norm)(deltas)
 
-        # drop clients whose surviving work is zero
-        contrib_mask = sel_mask * (eff_steps > 0)
-
-        # ---- buffered_async (plan_code 1): staleness-weighted arrivals ----
-        # FedBuff semantics, eager-application form: every contributor's
-        # update still lands this round, but discounted by how many K-sized
-        # buffer flushes precede its arrival — staleness s_i = floor(rank/K),
-        # weight (1+s)^-async_staleness_pow.  Arrival order comes from the
-        # failure-scenario engine (straggler slow factors × compute
-        # capacity), NOT from new draws, so every other lane's RNG stream is
-        # untouched.  On non-async lanes the weight is exactly 1.0 and
-        # contrib·1.0 is bitwise contrib — default lanes cannot move.
-        with jax.named_scope("async_buffer"):
-            arrive = fault_proc.arrival_score(slow, state.util.compute)
-            arrive = jnp.where(contrib_mask > 0, arrive, jnp.inf)
-            rank = jnp.argsort(jnp.argsort(arrive)).astype(jnp.float32)
-            stale = jnp.floor(rank / jnp.maximum(pr.async_buffer, 1.0))
-            stale_w = jnp.power(1.0 + stale, -pr.async_staleness_pow)
-            agg_mask = contrib_mask * jnp.where(pr.plan_code == 1.0, stale_w,
-                                                jnp.ones_like(stale_w))
-
-        # ---- hierarchical (plan_code 2): edge FedAvg -> cloud mean ----
-        # Client i reports to edge i % E (static shape, E = hierarchy_edges);
-        # each edge computes the same weighted FedAvg the flat plan does over
-        # its own group, and the cloud takes the unweighted mean over LIVE
-        # edges (edges whose contributor weight is nonzero).  Always lowered,
-        # selected away by a where on non-hier lanes — the fault engine's
-        # branch-free pattern.
-        with jax.named_scope("hier_aggregate"):
-            n_edges = max(int(fl.hierarchy_edges), 1)
-            edge_id = jnp.arange(n_clients) % n_edges
-            w_cli = (agg_mask * state.util.data_size).astype(jnp.float32)
-            edge_w = jnp.zeros((n_edges,), jnp.float32).at[edge_id].add(w_cli)
-            edge_live = (edge_w > 0).astype(jnp.float32)
-            n_live = jnp.maximum(jnp.sum(edge_live), 1.0)
-            is_hier = pr.plan_code == 2.0
-
-            def _hier_agg(d):
-                df = d.astype(jnp.float32)
-                wb = w_cli.reshape((-1,) + (1,) * (df.ndim - 1))
-                esum = jnp.zeros((n_edges,) + df.shape[1:],
-                                 jnp.float32).at[edge_id].add(df * wb)
-                tail = (1,) * (df.ndim - 1)
-                edelta = esum / jnp.maximum(edge_w, 1e-9).reshape((-1,) + tail)
-                live = edge_live.reshape((-1,) + tail)
-                return jnp.sum(edelta * live, axis=0) / n_live
-
-        # ---- aggregation + server update (line 18) ----
+        # ---- aggregation: async weights, edge tiers, server update ----
         with jax.named_scope("aggregate"):
+            # drop clients whose surviving work is zero
+            contrib_mask = sel_mask * (eff_steps > 0)
+
+            # ---- buffered_async (plan_code 1): staleness-weighted arrivals
+            # FedBuff semantics, eager-application form: every contributor's
+            # update still lands this round, but discounted by how many
+            # K-sized buffer flushes precede its arrival — staleness
+            # s_i = floor(rank/K), weight (1+s)^-async_staleness_pow.
+            # Arrival order comes from the failure-scenario engine (straggler
+            # slow factors × compute capacity), NOT from new draws, so every
+            # other lane's RNG stream is untouched.  On non-async lanes the
+            # weight is exactly 1.0 and contrib·1.0 is bitwise contrib —
+            # default lanes cannot move.
+            with jax.named_scope("async_buffer"):
+                arrive = fault_proc.arrival_score(slow, state.util.compute)
+                arrive = jnp.where(contrib_mask > 0, arrive, jnp.inf)
+                rank = jnp.argsort(jnp.argsort(arrive)).astype(jnp.float32)
+                stale = jnp.floor(rank / jnp.maximum(pr.async_buffer, 1.0))
+                stale_w = jnp.power(1.0 + stale, -pr.async_staleness_pow)
+                agg_mask = contrib_mask * jnp.where(pr.plan_code == 1.0,
+                                                    stale_w,
+                                                    jnp.ones_like(stale_w))
+
+            # ---- hierarchical (plan_code 2): edge FedAvg -> cloud mean ----
+            # Client i reports to edge i % E (static shape,
+            # E = hierarchy_edges); each edge computes the same weighted
+            # FedAvg the flat plan does over its own group, and the cloud
+            # takes the unweighted mean over LIVE edges (edges whose
+            # contributor weight is nonzero).  Always lowered, selected away
+            # by a where on non-hier lanes — the fault engine's branch-free
+            # pattern.
+            with jax.named_scope("hier_aggregate"):
+                n_edges = max(int(fl.hierarchy_edges), 1)
+                edge_id = jnp.arange(n_clients) % n_edges
+                w_cli = (agg_mask * state.util.data_size).astype(jnp.float32)
+                edge_w = jnp.zeros((n_edges,),
+                                   jnp.float32).at[edge_id].add(w_cli)
+                edge_live = (edge_w > 0).astype(jnp.float32)
+                n_live = jnp.maximum(jnp.sum(edge_live), 1.0)
+                is_hier = pr.plan_code == 2.0
+
+                def _hier_agg(d):
+                    df = d.astype(jnp.float32)
+                    wb = w_cli.reshape((-1,) + (1,) * (df.ndim - 1))
+                    esum = jnp.zeros((n_edges,) + df.shape[1:],
+                                     jnp.float32).at[edge_id].add(df * wb)
+                    tail = (1,) * (df.ndim - 1)
+                    edelta = esum / jnp.maximum(edge_w, 1e-9).reshape(
+                        (-1,) + tail)
+                    live = edge_live.reshape((-1,) + tail)
+                    return jnp.sum(edelta * live, axis=0) / n_live
+
+            # ---- aggregation + server update (line 18) ----
             agg_delta = agg.aggregate_stacked(deltas, agg_mask,
                                               state.util.data_size)
             agg_delta = jax.tree.map(
@@ -362,35 +370,38 @@ def make_parallel_round(loss_fn: Callable, fl: FLConfig, n_clients: int,
             new_params, new_server_state = _gate_server_update(
                 update_gate, new_params, new_server_state, state)
 
-        # ---- update-coherence (data-quality observable): cos(Δ_i, Δ_agg) ----
-        def _dot(a, b):
-            return sum(jnp.sum(x.astype(jnp.float32) * y.astype(jnp.float32))
-                       for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+            # ---- update-coherence (data-quality observable): cos(Δ_i, Δ_agg)
+            def _dot(a, b):
+                return sum(
+                    jnp.sum(x.astype(jnp.float32) * y.astype(jnp.float32))
+                    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
 
-        agg_norm = jnp.sqrt(jnp.maximum(_dot(agg_delta, agg_delta), 1e-18))
+            agg_norm = jnp.sqrt(jnp.maximum(_dot(agg_delta, agg_delta), 1e-18))
 
-        def _coh(delta_i):
-            num = sum(
-                jnp.sum(d.astype(jnp.float32) * g.astype(jnp.float32))
-                for d, g in zip(jax.tree.leaves(delta_i), jax.tree.leaves(agg_delta))
-            )
-            nrm = jnp.sqrt(jnp.maximum(_dot(delta_i, delta_i), 1e-18))
-            return num / (nrm * agg_norm)
+            def _coh(delta_i):
+                num = sum(
+                    jnp.sum(d.astype(jnp.float32) * g.astype(jnp.float32))
+                    for d, g in zip(jax.tree.leaves(delta_i),
+                                    jax.tree.leaves(agg_delta))
+                )
+                nrm = jnp.sqrt(jnp.maximum(_dot(delta_i, delta_i), 1e-18))
+                return num / (nrm * agg_norm)
 
-        if fl.coherence_scoring:
-            coherence = jax.vmap(_coh)(deltas) * contrib_mask
-        else:
-            coherence = None
+            if fl.coherence_scoring:
+                coherence = jax.vmap(_coh)(deltas) * contrib_mask
+            else:
+                coherence = None
 
-        # ---- bookkeeping ----
-        sel_denom = jnp.maximum(jnp.sum(contrib_mask), 1.0)
-        global_loss = jnp.sum(post_loss * contrib_mask) / sel_denom
-        failed_f = failed.astype(jnp.float32)
-        util = sel_lib.update_utility_state(state.util, contrib_mask, pre_loss,
-                                            post_loss, fl, coherence=coherence,
-                                            attempted=sel_mask, failed=failed_f)
-        kctl = sel_lib.update_k(state.kctl, global_loss, fl,
-                                tol=pr.k_tol, patience=pr.k_patience)
+        # ---- per-client carries: utility EMAs, adaptive K ----
+        with jax.named_scope("carry_update"):
+            sel_denom = jnp.maximum(jnp.sum(contrib_mask), 1.0)
+            global_loss = jnp.sum(post_loss * contrib_mask) / sel_denom
+            failed_f = failed.astype(jnp.float32)
+            util = sel_lib.update_utility_state(
+                state.util, contrib_mask, pre_loss, post_loss, fl,
+                coherence=coherence, attempted=sel_mask, failed=failed_f)
+            kctl = sel_lib.update_k(state.kctl, global_loss, fl,
+                                    tol=pr.k_tol, patience=pr.k_patience)
 
         new_state = RoundState(new_params, new_server_state, util, kctl,
                                state.round_idx + 1, rng, new_fault)
@@ -475,91 +486,107 @@ def make_cohort_round(loss_fn: Callable, fl: FLConfig, n_clients: int,
                    update_gate=None) -> Tuple[RoundState, CohortMetrics]:
         pr = default_params if params is None else params
         server = make_server_optimizer(fl.server_opt, pr.server_lr)
-        rng, k_avail, k_sel, k_fail, k_dp = jax.random.split(state.rng, 5)
 
-        # ---- O(N) population vector phase ----
-        avail = jax.random.bernoulli(k_avail, pr.avail_prob,
-                                     (n_clients,)).astype(jnp.float32)
-        utility = sel_lib.compute_utility(state.util, fl,
-                                          fault_w=pr.fault_util_w)
-        k_eff = (state.kctl.k if fl.adaptive_k
-                 else jnp.asarray(float(fl.clients_per_round), jnp.float32))
-        k_eff = jnp.minimum(k_eff, float(k_max))
-        scores = score_fn(k_sel, state.util, utility, avail,
-                          pr.explore_noise)
-        idx, take = sel_lib.cohort_topk(scores, avail, k_eff, k_max,
-                                        chunks=sel_chunks)
-        fail_at_full, slow_full, new_fault = fault_proc.fault_step(
-            state.fault, k_fail, pr, n_clients, local_steps)
+        # Phase scopes as in make_parallel_round, in round order; the O(N)
+        # population vector work is selection, fault and carry_update.
+        with jax.named_scope("selection"):
+            rng, k_avail, k_sel, k_fail, k_dp = jax.random.split(state.rng, 5)
+            avail = jax.random.bernoulli(k_avail, pr.avail_prob,
+                                         (n_clients,)).astype(jnp.float32)
+            utility = sel_lib.compute_utility(state.util, fl,
+                                              fault_w=pr.fault_util_w)
+            k_eff = (state.kctl.k if fl.adaptive_k
+                     else jnp.asarray(float(fl.clients_per_round),
+                                      jnp.float32))
+            k_eff = jnp.minimum(k_eff, float(k_max))
+            scores = score_fn(k_sel, state.util, utility, avail,
+                              pr.explore_noise)
+            idx, take = sel_lib.cohort_topk(scores, avail, k_eff, k_max,
+                                            chunks=sel_chunks)
+
+        with jax.named_scope("fault"):
+            fail_at_full, slow_full, new_fault = fault_proc.fault_step(
+                state.fault, k_fail, pr, n_clients, local_steps)
+            fail_frac = jnp.mean((fail_at_full < local_steps)
+                                 .astype(jnp.float32))
+            fail_at, slow = fault_proc.gather_cohort(fail_at_full, slow_full,
+                                                     idx)
+            eff_steps, failed = _effective_steps(
+                fail_at, local_steps, ckpt_every_steps, fl.fault_tolerance)
 
         # ---- cohort gather + O(k_max) training phase ----
-        fail_at, slow = fault_proc.gather_cohort(fail_at_full, slow_full, idx)
-        eff_steps, failed = _effective_steps(
-            fail_at, local_steps, ckpt_every_steps, fl.fault_tolerance)
-        batches = sample_fn(data_key, pop, idx)
-        deltas, pre_loss, post_loss = jax.vmap(
-            local_train, in_axes=(None, 0, 0, None)
-        )(state.params, batches, eff_steps, pr.local_lr)
+        with jax.named_scope("batch_sample"):
+            batches = sample_fn(data_key, pop, idx)
 
-        if fl.dp_enabled:
-            sigma = _dp_sigma(fl, pr)
-            keys = jax.vmap(lambda c: jax.random.fold_in(k_dp, c))(idx)
+        with jax.named_scope("local_train"):
+            deltas, pre_loss, post_loss = jax.vmap(
+                local_train, in_axes=(None, 0, 0, None)
+            )(state.params, batches, eff_steps, pr.local_lr)
 
-            def privatize(d, k):
-                return dp_lib.privatize_update(
-                    d, k, mode=fl.dp_mode, clip=pr.dp_clip, sigma=sigma,
-                    use_kernel=dp_use_kernel,
-                )
+        with jax.named_scope("dp_privatize"):
+            if fl.dp_enabled:
+                sigma = _dp_sigma(fl, pr)
+                keys = jax.vmap(lambda c: jax.random.fold_in(k_dp, c))(idx)
 
-            deltas, norms = jax.vmap(privatize)(deltas, keys)
-        else:
-            norms = jax.vmap(dp_lib.global_norm)(deltas)
+                def privatize(d, k):
+                    return dp_lib.privatize_update(
+                        d, k, mode=fl.dp_mode, clip=pr.dp_clip, sigma=sigma,
+                        use_kernel=dp_use_kernel,
+                    )
 
-        contrib = take * (eff_steps > 0)
-        agg_delta = agg.aggregate_stacked(deltas, contrib,
-                                          state.util.data_size[idx])
-        new_params, new_server_state = agg.apply_server_update(
-            server, state.params, state.server_opt_state, agg_delta)
-        new_params, new_server_state = _gate_server_update(
-            update_gate, new_params, new_server_state, state)
+                deltas, norms = jax.vmap(privatize)(deltas, keys)
+            else:
+                norms = jax.vmap(dp_lib.global_norm)(deltas)
 
-        if fl.coherence_scoring:
-            def _dot(a, b):
-                return sum(
-                    jnp.sum(x.astype(jnp.float32) * y.astype(jnp.float32))
-                    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+        with jax.named_scope("aggregate"):
+            contrib = take * (eff_steps > 0)
+            agg_delta = agg.aggregate_stacked(deltas, contrib,
+                                              state.util.data_size[idx])
+            new_params, new_server_state = agg.apply_server_update(
+                server, state.params, state.server_opt_state, agg_delta)
+            new_params, new_server_state = _gate_server_update(
+                update_gate, new_params, new_server_state, state)
 
-            agg_norm = jnp.sqrt(jnp.maximum(_dot(agg_delta, agg_delta),
-                                            1e-18))
+            if fl.coherence_scoring:
+                def _dot(a, b):
+                    return sum(
+                        jnp.sum(x.astype(jnp.float32) * y.astype(jnp.float32))
+                        for x, y in zip(jax.tree.leaves(a),
+                                        jax.tree.leaves(b)))
 
-            def _coh(delta_i):
-                num = sum(
-                    jnp.sum(d.astype(jnp.float32) * g.astype(jnp.float32))
-                    for d, g in zip(jax.tree.leaves(delta_i),
-                                    jax.tree.leaves(agg_delta)))
-                nrm = jnp.sqrt(jnp.maximum(_dot(delta_i, delta_i), 1e-18))
-                return num / (nrm * agg_norm)
+                agg_norm = jnp.sqrt(jnp.maximum(_dot(agg_delta, agg_delta),
+                                                1e-18))
 
-            coherence_c = jax.vmap(_coh)(deltas) * contrib
-        else:
-            coherence_c = None
+                def _coh(delta_i):
+                    num = sum(
+                        jnp.sum(d.astype(jnp.float32) * g.astype(jnp.float32))
+                        for d, g in zip(jax.tree.leaves(delta_i),
+                                        jax.tree.leaves(agg_delta)))
+                    nrm = jnp.sqrt(jnp.maximum(_dot(delta_i, delta_i), 1e-18))
+                    return num / (nrm * agg_norm)
+
+                coherence_c = jax.vmap(_coh)(deltas) * contrib
+            else:
+                coherence_c = None
 
         # ---- scatter back into the [N] carries ----
-        def scatter(vals_c):
-            return jnp.zeros((n_clients,), jnp.float32).at[idx].add(vals_c)
+        with jax.named_scope("carry_update"):
+            def scatter(vals_c):
+                return jnp.zeros((n_clients,), jnp.float32).at[idx].add(vals_c)
 
-        sel_full = scatter(take)
-        contrib_full = scatter(contrib)
-        failed_f = failed.astype(jnp.float32)
-        sel_denom = jnp.maximum(jnp.sum(contrib), 1.0)
-        global_loss = jnp.sum(post_loss * contrib) / sel_denom
-        util = sel_lib.update_utility_state(
-            state.util, contrib_full,
-            scatter(pre_loss * contrib), scatter(post_loss * contrib), fl,
-            coherence=None if coherence_c is None else scatter(coherence_c),
-            attempted=sel_full, failed=scatter(failed_f * take))
-        kctl = sel_lib.update_k(state.kctl, global_loss, fl,
-                                tol=pr.k_tol, patience=pr.k_patience)
+            sel_full = scatter(take)
+            contrib_full = scatter(contrib)
+            failed_f = failed.astype(jnp.float32)
+            sel_denom = jnp.maximum(jnp.sum(contrib), 1.0)
+            global_loss = jnp.sum(post_loss * contrib) / sel_denom
+            util = sel_lib.update_utility_state(
+                state.util, contrib_full,
+                scatter(pre_loss * contrib), scatter(post_loss * contrib), fl,
+                coherence=(None if coherence_c is None
+                           else scatter(coherence_c)),
+                attempted=sel_full, failed=scatter(failed_f * take))
+            kctl = sel_lib.update_k(state.kctl, global_loss, fl,
+                                    tol=pr.k_tol, patience=pr.k_patience)
 
         new_state = RoundState(new_params, new_server_state, util, kctl,
                                state.round_idx + 1, rng, new_fault)
@@ -567,8 +594,7 @@ def make_cohort_round(loss_fn: Callable, fl: FLConfig, n_clients: int,
             cohort_idx=idx, take=take, failed=failed_f * take,
             slow=slow, pre_loss=pre_loss, post_loss=post_loss,
             global_loss=global_loss, k_effective=k_eff, update_norms=norms,
-            fail_frac=jnp.mean((fail_at_full < local_steps)
-                               .astype(jnp.float32)))
+            fail_frac=fail_frac)
         return new_state, metrics
 
     return round_step

@@ -15,6 +15,11 @@ Two kernels:
 (:func:`repro.kernels.backend.resolve_interpret`): compiled Mosaic on
 TPU, interpret mode elsewhere.
 
+Named scopes split the step on a device trace: ``dp_layout`` is the pad to
+[rows, 128] tiles and back, ``dp_kernel`` the Pallas calls (named
+``dp_sumsq`` and ``dp_scale_noise``); ``kernels/ops.py`` adds ``dp_noise``
+around the noise draw.
+
 NOTE: validation runs in interpret mode on CPU where ``pltpu.prng_*`` has
 no lowering, so standard-normal noise is an explicit operand here.  On real
 TPU the noise read can be removed by seeding ``pltpu.prng_seed`` per tile and
@@ -55,31 +60,37 @@ def _scale_noise_kernel(scale_ref, x_ref, n_ref, o_ref, *, sigma: float):
 
 
 def _pad_2d(x, bt: int):
-    n = x.size
-    rows = -(-n // LANES)
-    rows_pad = -(-rows // bt) * bt
-    flat = jnp.zeros((rows_pad * LANES,), x.dtype).at[:n].set(x.reshape(-1))
-    return flat.reshape(rows_pad, LANES), rows_pad
+    """``x`` flattened and zero-padded to [rows, 128] with rows a multiple
+    of ``bt``."""
+    with jax.named_scope("dp_layout"):
+        n = x.size
+        rows = -(-n // LANES)
+        rows_pad = -(-rows // bt) * bt
+        flat = jnp.zeros((rows_pad * LANES,),
+                         x.dtype).at[:n].set(x.reshape(-1))
+        return flat.reshape(rows_pad, LANES), rows_pad
 
 
 def sumsq(x, *, bt: int = 256,
           interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Σ x² over a flat array, tiled [bt, 128] (zero-padded)."""
+    """Σ x² over every element of ``x``, tiled [bt, 128] (zero-padded)."""
     return _sumsq(x, bt=bt, interpret=backend.resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
 def _sumsq(x, *, bt: int, interpret: bool) -> jnp.ndarray:
     x2d, rows = _pad_2d(x, bt)
-    out = pl.pallas_call(
-        _sumsq_kernel,
-        grid=(rows // bt,),
-        in_specs=[pl.BlockSpec((bt, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        interpret=interpret,
-    )(x2d)
-    return out[0, 0]
+    with jax.named_scope("dp_kernel"):
+        out = pl.pallas_call(
+            _sumsq_kernel,
+            grid=(rows // bt,),
+            in_specs=[pl.BlockSpec((bt, LANES), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+            out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            interpret=interpret,
+            name="dp_sumsq",
+        )(x2d)
+        return out[0, 0]
 
 
 def scale_noise(x, noise, scale, *, sigma: float, bt: int = 256,
@@ -94,23 +105,26 @@ def _scale_noise(x, noise, scale, *, sigma: float, bt: int, interpret: bool):
     shape, n = x.shape, x.size
     x2d, rows = _pad_2d(x, bt)
     n2d, _ = _pad_2d(noise, bt)
-    # ``scale`` is a (1, 1) VMEM block equal to its whole array, so it stays
-    # legal under the (8, 128) tiling rule once the engine vmaps the call
-    # over clients (a whole-array SMEM block does not).
-    scale_arr = jnp.asarray(scale, jnp.float32).reshape(1, 1)
-    out = pl.pallas_call(
-        functools.partial(_scale_noise_kernel, sigma=float(sigma)),
-        grid=(rows // bt,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((bt, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((bt, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bt, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), x.dtype),
-        interpret=interpret,
-    )(scale_arr, x2d, n2d)
-    return out.reshape(-1)[:n].reshape(shape)
+    with jax.named_scope("dp_kernel"):
+        # ``scale`` is a (1, 1) VMEM block equal to its whole array, so it
+        # stays legal under the (8, 128) tiling rule once the engine vmaps
+        # the call over clients (a whole-array SMEM block does not).
+        scale_arr = jnp.asarray(scale, jnp.float32).reshape(1, 1)
+        out = pl.pallas_call(
+            functools.partial(_scale_noise_kernel, sigma=float(sigma)),
+            grid=(rows // bt,),
+            in_specs=[
+                pl.BlockSpec((1, 1), lambda i: (0, 0)),
+                pl.BlockSpec((bt, LANES), lambda i: (i, 0)),
+                pl.BlockSpec((bt, LANES), lambda i: (i, 0)),
+            ],
+            out_specs=pl.BlockSpec((bt, LANES), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((rows, LANES), x.dtype),
+            interpret=interpret,
+            name="dp_scale_noise",
+        )(scale_arr, x2d, n2d)
+    with jax.named_scope("dp_layout"):
+        return out.reshape(-1)[:n].reshape(shape)
 
 
 def dp_clip_noise(x, noise, clip: float, sigma: float, *, bt: int = 256,

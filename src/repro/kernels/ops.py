@@ -3,6 +3,7 @@ zoo and the FL core).  Each function dispatches to the kernel and is
 validated against ``repro.kernels.ref`` in tests/test_kernels.py."""
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -113,21 +114,29 @@ def dp_clip_noise_tree(tree, key, clip: float, sigma: float, *,
 
 def _dp_tree(tree, key, clip, sigma, interpret: bool):
     leaves, treedef = jax.tree.flatten(tree)
-    total = sum(
-        _dp.sumsq(l.reshape(-1), interpret=interpret) for l in leaves
-    )
+    total = sum(_dp.sumsq(l, interpret=interpret) for l in leaves)
     norm = jnp.sqrt(total)
     scale = jnp.minimum(1.0, clip / jnp.maximum(norm, 1e-12))
     sigma_static = isinstance(sigma, (int, float))
-    keys = jax.random.split(key, len(leaves))
-    out = []
-    for leaf, k in zip(leaves, keys):
-        noise = jax.random.normal(k, leaf.shape, jnp.float32)
-        if not sigma_static:
-            noise = sigma * noise
-        out.append(
-            _dp.scale_noise(leaf, noise, scale,
-                            sigma=float(sigma) if sigma_static else 1.0,
-                            interpret=interpret)
-        )
+    noise = _dp_noise(key, tuple(l.shape for l in leaves),
+                      None if sigma_static else sigma)
+    out = [
+        _dp.scale_noise(leaf, n, scale,
+                        sigma=float(sigma) if sigma_static else 1.0,
+                        interpret=interpret)
+        for leaf, n in zip(leaves, noise)
+    ]
     return jax.tree.unflatten(treedef, out), norm
+
+
+@functools.partial(jax.jit, static_argnames=("shapes",))
+def _dp_noise(key, shapes, sigma):
+    """One standard-normal draw per leaf (key-split order = leaf order),
+    times ``sigma`` unless it is None (a static sigma is baked into the
+    kernel instead).  Jitted so that ``dp_noise`` stays one component of the
+    ops' scope path under the engine's client ``vmap``."""
+    with jax.named_scope("dp_noise"):
+        keys = jax.random.split(key, len(shapes))
+        noise = [jax.random.normal(k, s, jnp.float32)
+                 for k, s in zip(keys, shapes)]
+        return noise if sigma is None else [sigma * n for n in noise]

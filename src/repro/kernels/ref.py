@@ -72,12 +72,15 @@ def dp_clip_noise_tree_ref(tree, key, clip: float, sigma: float):
         sum(jnp.sum(jnp.square(l.astype(jnp.float32))) for l in leaves)
     )
     scale = jnp.minimum(1.0, clip / jnp.maximum(norm, 1e-12))
-    keys = jax.random.split(key, len(leaves))
-    out = [
-        (l.astype(jnp.float32) * scale
-         + sigma * jax.random.normal(k, l.shape, jnp.float32)).astype(l.dtype)
-        for l, k in zip(leaves, keys)
-    ]
+    with jax.named_scope("dp_noise"):
+        keys = jax.random.split(key, len(leaves))
+
+    def noised(l, k):
+        with jax.named_scope("dp_noise"):       # as kernels/ops.py draws it
+            noise = jax.random.normal(k, l.shape, jnp.float32)
+        return (l.astype(jnp.float32) * scale + sigma * noise).astype(l.dtype)
+
+    out = [noised(l, k) for l, k in zip(leaves, keys)]
     return jax.tree.unflatten(treedef, out), norm
 
 

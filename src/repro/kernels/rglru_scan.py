@@ -72,23 +72,25 @@ def _rglru_scan(a, x, h0, *, chunk: int, bw: int, interpret: bool):
     # the TPU lowering needs for any B (a (1, bw) block on [B, W] is legal
     # only when B == 1).
     grid = (b, w // bw, l // chunk)
-    h, hlast = pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bw), lambda bi, wi, ci: (bi, 0, wi)),
-            pl.BlockSpec((1, chunk, bw), lambda bi, wi, ci: (bi, ci, wi)),
-            pl.BlockSpec((1, chunk, bw), lambda bi, wi, ci: (bi, ci, wi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, bw), lambda bi, wi, ci: (bi, ci, wi)),
-            pl.BlockSpec((1, 1, bw), lambda bi, wi, ci: (bi, 0, wi)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, l, w), jnp.float32),
-            jax.ShapeDtypeStruct((b, 1, w), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, 1, bw), jnp.float32)],
-        interpret=interpret,
-    )(h0.astype(jnp.float32).reshape(b, 1, w), a, x)
+    with jax.named_scope("rglru_scan"):
+        h, hlast = pl.pallas_call(
+            functools.partial(_kernel, chunk=chunk),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, bw), lambda bi, wi, ci: (bi, 0, wi)),
+                pl.BlockSpec((1, chunk, bw), lambda bi, wi, ci: (bi, ci, wi)),
+                pl.BlockSpec((1, chunk, bw), lambda bi, wi, ci: (bi, ci, wi)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, chunk, bw), lambda bi, wi, ci: (bi, ci, wi)),
+                pl.BlockSpec((1, 1, bw), lambda bi, wi, ci: (bi, 0, wi)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, l, w), jnp.float32),
+                jax.ShapeDtypeStruct((b, 1, w), jnp.float32),
+            ],
+            scratch_shapes=[pltpu.VMEM((1, 1, bw), jnp.float32)],
+            interpret=interpret,
+            name="rglru_scan",
+        )(h0.astype(jnp.float32).reshape(b, 1, w), a, x)
     return h, hlast.reshape(b, w)

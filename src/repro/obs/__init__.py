@@ -4,8 +4,9 @@ Three coupled pieces, all HOST-side — nothing here enters a traced
 function, so every training/eval lane is bitwise identical with telemetry
 on or off (tests/test_obs.py pins it):
 
-* ``obs/trace.py`` — nested host spans (wall + process time), compile-event
-  capture keyed on the runner/scorer caches, structured JSONL emission, and
+* ``obs/trace.py`` — nested host spans (epoch-ns and wall clocks),
+  compile-event capture keyed on the runner/scorer caches, structured JSONL
+  emission, and
   the ``jax.profiler`` integration (``profile_trace`` dumps a
   TensorBoard-loadable trace; spans double as
   ``jax.profiler.TraceAnnotation`` phase markers while profiling).

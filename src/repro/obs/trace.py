@@ -3,8 +3,8 @@
 Design constraints (docs/DESIGN.md §8):
 
 * **Bitwise neutrality** — nothing here runs inside a traced function.
-  Spans time HOST phases (upload, dispatch, readback, compile) with wall
-  and process clocks; device-side phase markers are ``jax.named_scope``
+  Spans time HOST phases (upload, dispatch, readback, compile); device-side
+  phase markers are ``jax.named_scope``
   annotations placed at the instrumentation sites themselves (metadata
   only — they never change the lowered math).
 * **Zero cost when off** — the tracer is disabled by default and
@@ -15,12 +15,19 @@ Design constraints (docs/DESIGN.md §8):
 * **Structured emission** — spans/events append to an in-memory buffer
   and, when enabled with a path (or ``REPRO_TRACE=<path>`` in the
   environment), stream to JSONL one object per line: ``{"type": "span" |
-  "event", "name", "t0", "wall_s", "cpu_s", "depth", "parent", ...attrs}``.
+  "event", "name", "t0", "t0_ns", "t1_ns", "wall_s", "depth", "parent",
+  ...attrs}``.
+* **One clock with the device trace** — ``t0_ns``/``t1_ns`` are
+  ``time.time_ns()``, the realtime clock whose value at the profile's
+  start the trace stores (``profile_start_time`` in its ``Task
+  Environment`` plane); ``t0`` is the same instant in epoch seconds and
+  ``wall_s`` the ``perf_counter`` duration.
 * **Profiler integration** — :func:`profile_trace` wraps
   ``jax.profiler.start_trace``/``stop_trace`` (the ``--profile`` flag on
   ``benchmarks/run.py`` and ``python -m repro.serve``); while profiling,
-  every span ALSO enters a ``jax.profiler.TraceAnnotation`` so host phases
-  line up with the device timeline in TensorBoard.
+  every span ALSO enters a ``jax.profiler.TraceAnnotation`` carrying the
+  span's index (``span=<index>``) and attributes as stats, so host phases
+  line up with the device timeline and each annotation names its span.
 """
 from __future__ import annotations
 
@@ -38,19 +45,24 @@ class Span:
     """One closed host span (or an open one still on the stack)."""
 
     name: str
-    t0: float                      # time.time() at entry (epoch seconds)
+    t0_ns: int = 0                 # time.time_ns() at entry (epoch ns)
+    t1_ns: int = 0                 # time.time_ns() at exit
     wall_s: float = 0.0            # perf_counter delta
-    cpu_s: float = 0.0             # process_time delta
     depth: int = 0
     index: int = 0                 # position in the tracer's span list
     parent: int = -1               # index of the enclosing span (-1 = root)
     attrs: Dict[str, Any] = field(default_factory=dict)
 
+    @property
+    def t0(self) -> float:
+        """Entry time in epoch seconds."""
+        return self.t0_ns * 1e-9
+
     def to_json(self) -> Dict[str, Any]:
         return {"type": "span", "name": self.name, "t0": self.t0,
-                "wall_s": self.wall_s, "cpu_s": self.cpu_s,
-                "depth": self.depth, "index": self.index,
-                "parent": self.parent, **self.attrs}
+                "t0_ns": self.t0_ns, "t1_ns": self.t1_ns,
+                "wall_s": self.wall_s, "depth": self.depth,
+                "index": self.index, "parent": self.parent, **self.attrs}
 
 
 class _NullCm:
@@ -120,46 +132,47 @@ class Tracer:
 
     @contextmanager
     def _span_cm(self, name: str, attrs: Dict[str, Any]):
-        ann = None
-        if self.profiling:          # host phase marker on the TB timeline
-            import jax
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
         if not self.enabled:        # profiling-only: annotate, don't record
-            try:
+            import jax
+            with jax.profiler.TraceAnnotation(name, **attrs):
                 yield None
-            finally:
-                ann.__exit__(None, None, None)
             return
         with self._lock:
-            sp = Span(name=name, t0=time.time(), depth=len(self._stack),
+            sp = Span(name=name, depth=len(self._stack),
                       index=len(self.spans),
                       parent=self._stack[-1].index if self._stack else -1,
                       attrs=dict(attrs))
             self.spans.append(sp)
             self._stack.append(sp)
-        w0, c0 = time.perf_counter(), time.process_time()
+        ann = None
+        if self.profiling:          # host phase marker on the trace timeline
+            import jax
+            ann = jax.profiler.TraceAnnotation(name, span=sp.index, **attrs)
+            ann.__enter__()
+        sp.t0_ns, w0 = time.time_ns(), time.perf_counter()
         try:
             yield sp
         finally:
             sp.wall_s = time.perf_counter() - w0
-            sp.cpu_s = time.process_time() - c0
+            sp.t1_ns = time.time_ns()
+            if ann is not None:
+                ann.__exit__(None, None, None)
             with self._lock:
                 if self._stack and self._stack[-1] is sp:
                     self._stack.pop()
                 if self._jsonl is not None:
                     self._jsonl.write(json.dumps(sp.to_json()) + "\n")
                     self._jsonl.flush()
-            if ann is not None:
-                ann.__exit__(None, None, None)
 
     def event(self, name: str, **attrs) -> None:
         """Record a point event (e.g. a compile-cache miss).  No-op while
         disabled."""
         if not self.enabled:
             return
+        t_ns = time.time_ns()
         with self._lock:
-            ev = {"type": "event", "name": name, "t0": time.time(),
+            ev = {"type": "event", "name": name, "t0": t_ns * 1e-9,
+                  "t0_ns": t_ns, "t1_ns": t_ns,
                   "depth": len(self._stack),
                   "parent": self._stack[-1].index if self._stack else -1,
                   **attrs}
@@ -211,12 +224,20 @@ def profile_trace(logdir: str):
     """Dump a TensorBoard-loadable ``jax.profiler`` trace of the block to
     ``logdir`` (the ``--profile`` flag's implementation).  While active,
     host spans double as ``TraceAnnotation`` phase markers, and the
-    device-side ``jax.named_scope`` markers (round_step / eval_block /
-    cohort_topk / serve dispatch) appear in the XLA op names."""
+    device-side ``jax.named_scope`` markers (the round step's phases,
+    ``eval_block``, the DP kernels' ``dp_layout``/``dp_noise``/
+    ``dp_kernel``) reach the trace in the HLO's ``op_name`` metadata.  The
+    options are the benchmark's (``bench/harness.py::Profiler``): no
+    Python tracer, host annotations, and the HLO protos, so the trace
+    reduces with ``bench/trace/xplane.py``."""
     import jax
 
     os.makedirs(logdir, exist_ok=True)
-    jax.profiler.start_trace(logdir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    opts.enable_hlo_proto = True
+    jax.profiler.start_trace(logdir, profiler_options=opts)
     TRACER.profiling = True
     try:
         yield logdir

@@ -392,32 +392,40 @@ def _build_single_run(fl: FLConfig, rounds: int, eval_every: int,
                 state, data_key, cum_time, acct, sched = carry
             else:
                 state, data_key, cum_time = carry
-            data_key, k_batch = jax.random.split(data_key)
-            batches = sample_round_batches(k_batch, stack, fl.local_epochs,
-                                           fl.local_batch)
+            # phase scopes of the round loop around the round step
+            # (core/rounds.py names the step's own): metadata only
+            with jax.named_scope("batch_sample"):
+                data_key, k_batch = jax.random.split(data_key)
+                batches = sample_round_batches(k_batch, stack,
+                                               fl.local_epochs, fl.local_batch)
             if scheduled:
-                k_eff = state.kctl.k if fl.adaptive_k else k_static
-                # compose at the REALISED cohort fraction — _topk_mask
-                # selects ceil(k_eff) clients, not k_eff (ISSUE 4 bugfix)
-                q_t = realized_cohort_fraction(k_eff, n_clients)
-                z_t = sched_lib.scheduled_multiplier(sched, pr,
-                                                     state.round_idx, rounds)
-                sigma_t = z_t * pr.dp_clip
-                acct_next = acct_lib.accountant_step(acct, z_t, q_t)
-                eps_next = acct_lib.epsilon_from_state(acct_next, fl.dp_delta)
-                live = (eps_next <= pr.dp_budget).astype(jnp.float32)
+                with jax.named_scope("bookkeeping"):
+                    k_eff = state.kctl.k if fl.adaptive_k else k_static
+                    # compose at the REALISED cohort fraction — _topk_mask
+                    # selects ceil(k_eff) clients, not k_eff
+                    q_t = realized_cohort_fraction(k_eff, n_clients)
+                    z_t = sched_lib.scheduled_multiplier(
+                        sched, pr, state.round_idx, rounds)
+                    sigma_t = z_t * pr.dp_clip
+                    acct_next = acct_lib.accountant_step(acct, z_t, q_t)
+                    eps_next = acct_lib.epsilon_from_state(acct_next,
+                                                           fl.dp_delta)
+                    live = (eps_next <= pr.dp_budget).astype(jnp.float32)
                 state, m = round_step(state, batches,
                                       pr._replace(dp_sigma=sigma_t),
                                       update_gate=live)
-                # spend the budget only for released rounds
-                acct = jax.tree.map(lambda n, o: jnp.where(live > 0, n, o),
-                                    acct_next, acct)
             else:
                 state, m = round_step(state, batches, pr)
-            cum_time = cum_time + simulate_round_time(fl, state.util,
-                                                      m.sel_mask, m.failed,
-                                                      params=pr, slow=m.slow)
-            fail_mean = jnp.mean(m.failed)
+            with jax.named_scope("bookkeeping"):
+                if scheduled:
+                    # spend the budget only for released rounds
+                    acct = jax.tree.map(
+                        lambda n, o: jnp.where(live > 0, n, o), acct_next,
+                        acct)
+                cum_time = cum_time + simulate_round_time(
+                    fl, state.util, m.sel_mask, m.failed, params=pr,
+                    slow=m.slow)
+                fail_mean = jnp.mean(m.failed)
             if scheduled:
                 return ((state, data_key, cum_time, acct, sched),
                         (m.global_loss, m.k_effective, fail_mean, sigma_t,
@@ -714,42 +722,45 @@ def run_fl_sweep(
     with obs_trace.span("sweep.readback", n_lanes=n_lanes):
         trace_np = {k: np.asarray(v) for k, v in trace_b.items()}
         sim_np = np.asarray(sim_b)
-    # one spec for every lane (model is static) — rebuilding per lane would
-    # defeat _personalize's jit cache for closure-built specs
-    spec = get_model_spec(fl.model, meta) if method == "fedl2p" else None
-    out: List[List[RunResult]] = []
-    for ci, cell in enumerate(cells):
-        # fixed-σ cells: host closed-form composition (engine-independent);
-        # scheduled cells: ε comes from the lane's in-scan accountant trace
-        scheduled = cell.dp_enabled and cell.dp_scheduled
-        eps_cell = None if scheduled else accounted_epsilon(cell, rounds)
-        row = []
-        for si, seed in enumerate(seeds):
-            lane = ci * len(seeds) + si
-            history = {"round": [r + 1 for r in eval_idx]}
-            for name in trace_np:
-                history[name] = [float(x) for x in trace_np[name][lane]]
-            eps = history["eps"][-1] if scheduled else eps_cell
-            sim_time = float(sim_np[lane])
-            acc, auc = history["acc"][-1], history["auc"][-1]
-            if method == "fedl2p":
-                # personalisation pass (the point of FedL2P) + simulated cost
-                acc, auc = _personalize(
-                    jax.tree.map(lambda x: x[lane], params_b), fed, spec,
-                    seed=seed)
-                sim_time *= 1.2
-            lane_params = None
-            if return_params:
-                lane_params = jax.tree.map(lambda x: np.asarray(x[lane]),
-                                           params_b)
-            row.append(RunResult(
-                method=method, dataset=dataset, seed=seed,
-                accuracy=acc, auc=auc,
-                sim_time_s=sim_time, wall_time_s=wall_per_lane,
-                rounds=rounds, eps_spent=eps, history=history,
-                params=lane_params,
-            ))
-        out.append(row)
+    with obs_trace.span("sweep.results", n_lanes=n_lanes):
+        # one spec for every lane (model is static) — rebuilding per lane
+        # would defeat _personalize's jit cache for closure-built specs
+        spec = get_model_spec(fl.model, meta) if method == "fedl2p" else None
+        out: List[List[RunResult]] = []
+        for ci, cell in enumerate(cells):
+            # fixed-σ cells: host closed-form composition (engine-
+            # independent); scheduled cells: ε comes from the lane's in-scan
+            # accountant trace
+            scheduled = cell.dp_enabled and cell.dp_scheduled
+            eps_cell = None if scheduled else accounted_epsilon(cell, rounds)
+            row = []
+            for si, seed in enumerate(seeds):
+                lane = ci * len(seeds) + si
+                history = {"round": [r + 1 for r in eval_idx]}
+                for name in trace_np:
+                    history[name] = [float(x) for x in trace_np[name][lane]]
+                eps = history["eps"][-1] if scheduled else eps_cell
+                sim_time = float(sim_np[lane])
+                acc, auc = history["acc"][-1], history["auc"][-1]
+                if method == "fedl2p":
+                    # personalisation pass (the point of FedL2P) + its
+                    # simulated cost
+                    acc, auc = _personalize(
+                        jax.tree.map(lambda x: x[lane], params_b), fed, spec,
+                        seed=seed)
+                    sim_time *= 1.2
+                lane_params = None
+                if return_params:
+                    lane_params = jax.tree.map(lambda x: np.asarray(x[lane]),
+                                               params_b)
+                row.append(RunResult(
+                    method=method, dataset=dataset, seed=seed,
+                    accuracy=acc, auc=auc,
+                    sim_time_s=sim_time, wall_time_s=wall_per_lane,
+                    rounds=rounds, eps_spent=eps, history=history,
+                    params=lane_params,
+                ))
+            out.append(row)
     return out
 
 
@@ -848,30 +859,38 @@ def _build_population_run(fl: FLConfig, rounds: int, eval_every: int,
                 state, data_key, cum_time, acct, sched = carry
             else:
                 state, data_key, cum_time = carry
-            data_key, k_batch = jax.random.split(data_key)
+            # phase scopes as in _build_single_run; the cohort's own
+            # batch_sample scope sits inside make_cohort_round
+            with jax.named_scope("batch_sample"):
+                data_key, k_batch = jax.random.split(data_key)
             if scheduled:
-                k_eff = state.kctl.k if fl.adaptive_k else k_static
-                # the cohort plan caps the controller at the static cohort
-                # size, so the accountant must see the same realised k
-                k_eff = jnp.minimum(k_eff, k_cap)
-                q_t = realized_cohort_fraction(k_eff, n_clients)
-                z_t = sched_lib.scheduled_multiplier(sched, pr,
-                                                     state.round_idx, rounds)
-                sigma_t = z_t * pr.dp_clip
-                acct_next = acct_lib.accountant_step(acct, z_t, q_t)
-                eps_next = acct_lib.epsilon_from_state(acct_next, fl.dp_delta)
-                live = (eps_next <= pr.dp_budget).astype(jnp.float32)
+                with jax.named_scope("bookkeeping"):
+                    k_eff = state.kctl.k if fl.adaptive_k else k_static
+                    # the cohort plan caps the controller at the static
+                    # cohort size, so the accountant must see the same k
+                    k_eff = jnp.minimum(k_eff, k_cap)
+                    q_t = realized_cohort_fraction(k_eff, n_clients)
+                    z_t = sched_lib.scheduled_multiplier(
+                        sched, pr, state.round_idx, rounds)
+                    sigma_t = z_t * pr.dp_clip
+                    acct_next = acct_lib.accountant_step(acct, z_t, q_t)
+                    eps_next = acct_lib.epsilon_from_state(acct_next,
+                                                           fl.dp_delta)
+                    live = (eps_next <= pr.dp_budget).astype(jnp.float32)
                 state, m = round_step(state, pop, k_batch,
                                       pr._replace(dp_sigma=sigma_t),
                                       update_gate=live)
-                acct = jax.tree.map(lambda a, o: jnp.where(live > 0, a, o),
-                                    acct_next, acct)
             else:
                 state, m = round_step(state, pop, k_batch, pr)
-            util_view = state.util._replace(
-                compute=state.util.compute[m.cohort_idx])
-            cum_time = cum_time + simulate_round_time(
-                fl, util_view, m.take, m.failed, params=pr, slow=m.slow)
+            with jax.named_scope("bookkeeping"):
+                if scheduled:
+                    acct = jax.tree.map(
+                        lambda a, o: jnp.where(live > 0, a, o), acct_next,
+                        acct)
+                util_view = state.util._replace(
+                    compute=state.util.compute[m.cohort_idx])
+                cum_time = cum_time + simulate_round_time(
+                    fl, util_view, m.take, m.failed, params=pr, slow=m.slow)
             if scheduled:
                 return ((state, data_key, cum_time, acct, sched),
                         (m.global_loss, m.k_effective, m.fail_frac, sigma_t,
@@ -1053,94 +1072,104 @@ def run_fl_population(
         return []
     n_lanes = len(cells) * len(seeds)
 
-    meta = meta_for(pop, hidden=hidden)
-    spec = get_model_spec(fl.model, meta)
-    model_bytes = spec.param_bytes()
+    with obs_trace.span("population.prepare", method=method, n_lanes=n_lanes,
+                        n_cells=len(cells), rounds=rounds,
+                        plans=",".join(sorted({c.plan for c in cells}))):
+        meta = meta_for(pop, hidden=hidden)
+        spec = get_model_spec(fl.model, meta)
+        model_bytes = spec.param_bytes()
 
-    if sel_chunks is None:
-        sel_chunks = 1 if memory_budget_bytes is None else scale_lib.auto_chunks(
-            pop.n_clients, int(memory_budget_bytes),
-            pop.members_per_client, n_lanes, model_bytes=model_bytes)
+        if sel_chunks is None:
+            sel_chunks = 1 if memory_budget_bytes is None else \
+                scale_lib.auto_chunks(pop.n_clients, int(memory_budget_bytes),
+                                      pop.members_per_client, n_lanes,
+                                      model_bytes=model_bytes)
 
-    mesh = make_scale_mesh(n_lanes, shape=mesh_shape) if shard else None
-    n_padded = n_lanes
-    if mesh is not None:
-        lane_size = mesh.shape["lane"]
-        n_padded = -(-n_lanes // lane_size) * lane_size
+        mesh = make_scale_mesh(n_lanes, shape=mesh_shape) if shard else None
+        n_padded = n_lanes
+        if mesh is not None:
+            lane_size = mesh.shape["lane"]
+            n_padded = -(-n_lanes // lane_size) * lane_size
 
-    # ModelSpec sharding hook: when the detector's replicated parameter
-    # footprint exceeds the budget (core/scale.py) AND the spec declares
-    # param_axes, trace the runner under the RULES_MODEL_SCALE context so
-    # the spec's constrain_params calls tensor-parallel the model over the
-    # mesh's client axis.  The decision is part of the runner-cache key —
-    # sharded and replicated traces are different programs.
-    model_ctx = contextlib.nullcontext()
-    model_shard_key = None
-    if (mesh is not None and mesh.shape["client"] > 1
-            and spec.param_axes is not None
-            and scale_lib.model_needs_sharding(model_bytes,
-                                               model_replicated_max_bytes)):
-        model_ctx = shardctx.sharding_ctx(shard_lib.RULES_MODEL_SCALE, mesh)
-        model_shard_key = tuple(sorted(mesh.shape.items()))
+        # ModelSpec sharding hook: when the detector's replicated parameter
+        # footprint exceeds the budget (core/scale.py) AND the spec declares
+        # param_axes, trace the runner under the RULES_MODEL_SCALE context so
+        # the spec's constrain_params calls tensor-parallel the model over the
+        # mesh's client axis.  The decision is part of the runner-cache key —
+        # sharded and replicated traces are different programs.
+        model_ctx = contextlib.nullcontext()
+        model_shard_key = None
+        if (mesh is not None and mesh.shape["client"] > 1
+                and spec.param_axes is not None
+                and scale_lib.model_needs_sharding(model_bytes,
+                                                   model_replicated_max_bytes)):
+            model_ctx = shardctx.sharding_ctx(shard_lib.RULES_MODEL_SCALE,
+                                              mesh)
+            model_shard_key = tuple(sorted(mesh.shape.items()))
 
-    t0 = time.time()
-    runner = _get_population_runner(fl, rounds, eval_every, meta, n_padded,
-                                    pop, sel_chunks,
-                                    model_shard_key=model_shard_key,
-                                    sharded=mesh is not None)
-    keys = jax.vmap(jax.random.key)(
-        jnp.asarray(np.tile(seeds, len(cells)), jnp.uint32))
-    lanes = _params_lanes(cells, len(seeds))
-    if n_padded > n_lanes:
-        pad = n_padded - n_lanes
-        keys = jnp.concatenate([keys, jnp.repeat(keys[-1:], pad, axis=0)])
-        lanes = jax.tree.map(
-            lambda x: jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)]),
-            lanes)
+        t0 = time.time()
+        runner = _get_population_runner(fl, rounds, eval_every, meta, n_padded,
+                                        pop, sel_chunks,
+                                        model_shard_key=model_shard_key,
+                                        sharded=mesh is not None)
+        keys = jax.vmap(jax.random.key)(
+            jnp.asarray(np.tile(seeds, len(cells)), jnp.uint32))
+        lanes = _params_lanes(cells, len(seeds))
+        if n_padded > n_lanes:
+            pad = n_padded - n_lanes
+            keys = jnp.concatenate([keys, jnp.repeat(keys[-1:], pad, axis=0)])
+            lanes = jax.tree.map(
+                lambda x: jnp.concatenate(
+                    [x, jnp.repeat(x[-1:], pad, axis=0)]),
+                lanes)
 
-    if mesh is not None:
-        s_lane, _ = shard_lib.lane_shardings(mesh)
-        keys = jax.device_put(keys, s_lane)
-        lanes = jax.tree.map(lambda x: jax.device_put(x, s_lane), lanes)
-        if pop.n_clients % mesh.shape["client"] == 0:
-            pop = jax.device_put(pop, shard_lib.population_shardings(mesh, pop))
-        else:
-            # uneven client axis: replicate rather than shard (correct but
-            # unscaled — pad the population to a device multiple to shard)
-            rep = NamedSharding(mesh, PartitionSpec())
-            pop = jax.device_put(pop, jax.tree.map(lambda _: rep, pop))
+        if mesh is not None:
+            s_lane, _ = shard_lib.lane_shardings(mesh)
+            keys = jax.device_put(keys, s_lane)
+            lanes = jax.tree.map(lambda x: jax.device_put(x, s_lane), lanes)
+            if pop.n_clients % mesh.shape["client"] == 0:
+                pop = jax.device_put(
+                    pop, shard_lib.population_shardings(mesh, pop))
+            else:
+                # uneven client axis: replicate rather than shard (correct but
+                # unscaled — pad the population to a device multiple to shard)
+                rep = NamedSharding(mesh, PartitionSpec())
+                pop = jax.device_put(pop, jax.tree.map(lambda _: rep, pop))
 
     # the Pallas kernels, which XLA cannot partition, read this mesh to run
     # per device (kernels/ops.py::per_device)
     mesh_ctx = (jax.set_mesh(mesh) if mesh is not None
                 else contextlib.nullcontext())
-    with model_ctx, mesh_ctx:
-        params_b, sim_b, trace_b = runner(keys, pop, lanes)
-    jax.block_until_ready(sim_b)
+    with obs_trace.span("population.execute", n_lanes=n_lanes):
+        with model_ctx, mesh_ctx:
+            params_b, sim_b, trace_b = runner(keys, pop, lanes)
+        jax.block_until_ready(sim_b)
     wall_per_lane = (time.time() - t0) / max(n_lanes, 1)
 
     eval_idx = _eval_rounds(rounds, eval_every)
-    trace_np = {k: np.asarray(v) for k, v in trace_b.items()}
-    sim_np = np.asarray(sim_b)
+    with obs_trace.span("population.readback", n_lanes=n_lanes):
+        trace_np = {k: np.asarray(v) for k, v in trace_b.items()}
+        sim_np = np.asarray(sim_b)
     out: List[List[RunResult]] = []
-    for ci, cell in enumerate(cells):
-        scheduled = cell.dp_enabled and cell.dp_scheduled
-        eps_cell = None if scheduled else accounted_epsilon(cell, rounds)
-        row = []
-        for si, seed in enumerate(seeds):
-            lane = ci * len(seeds) + si
-            history = {"round": [r + 1 for r in eval_idx]}
-            for name in trace_np:
-                history[name] = [float(x) for x in trace_np[name][lane]]
-            row.append(RunResult(
-                method=method, dataset=dataset, seed=seed,
-                accuracy=history["acc"][-1], auc=history["auc"][-1],
-                sim_time_s=float(sim_np[lane]), wall_time_s=wall_per_lane,
-                rounds=rounds,
-                eps_spent=history["eps"][-1] if scheduled else eps_cell,
-                history=history,
-            ))
-        out.append(row)
+    with obs_trace.span("population.results", n_lanes=n_lanes):
+        for ci, cell in enumerate(cells):
+            scheduled = cell.dp_enabled and cell.dp_scheduled
+            eps_cell = None if scheduled else accounted_epsilon(cell, rounds)
+            row = []
+            for si, seed in enumerate(seeds):
+                lane = ci * len(seeds) + si
+                history = {"round": [r + 1 for r in eval_idx]}
+                for name in trace_np:
+                    history[name] = [float(x) for x in trace_np[name][lane]]
+                row.append(RunResult(
+                    method=method, dataset=dataset, seed=seed,
+                    accuracy=history["acc"][-1], auc=history["auc"][-1],
+                    sim_time_s=float(sim_np[lane]), wall_time_s=wall_per_lane,
+                    rounds=rounds,
+                    eps_spent=history["eps"][-1] if scheduled else eps_cell,
+                    history=history,
+                ))
+            out.append(row)
     return out
 
 

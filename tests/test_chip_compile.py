@@ -118,6 +118,47 @@ def test_dp_clip_noise_tree_vmapped_over_clients(one_chip, compiled_kernels):
     assert count >= 2 * n_leaves    # one sumsq + one scale_noise per leaf
 
 
+def _kernel_calls(hlo: str):
+    """(instruction name, op_name) of every Mosaic kernel call."""
+    out = []
+    for line in hlo.splitlines():
+        if CUSTOM_CALL in line:
+            name = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", line).group(1)
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            out.append((name, op_name.group(1) if op_name else ""))
+    return out
+
+
+def test_kernels_keep_their_names_and_scopes(one_chip, compiled_kernels):
+    """The custom call of a Pallas kernel is named after its ``name=``
+    (``dp_sumsq.N``, ``dp_scale_noise.N``, ``rglru_scan.N``), and its
+    ``op_name`` holds the scope a trace reader looks for."""
+    spec = get_model_spec("mlp", UNSW)
+    leaf = jax.eval_shape(spec.init, jax.random.key(0))
+    deltas = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((N_CLIENTS,) + s.shape, s.dtype), leaf)
+    raw_keys = jax.ShapeDtypeStruct((N_CLIENTS, 2), jnp.uint32)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32)
+
+    def privatize(d, raw, clip, sigma):
+        keys = jax.random.wrap_key_data(raw)
+        return jax.vmap(lambda t, k: ops.dp_clip_noise_tree(
+            t, k, clip, sigma, interpret=False))(d, keys)
+
+    hlo = jax.jit(privatize).lower(*_on(one_chip, (deltas, raw_keys, scalar,
+                                                   scalar))).compile().as_text()
+    calls = _kernel_calls(hlo)
+    assert {n.split(".")[0] for n, _ in calls} == {"dp_sumsq", "dp_scale_noise"}
+    assert all("/dp_kernel/" in op for _, op in calls), calls
+
+    seq = jax.ShapeDtypeStruct((8, 128, 512), jnp.float32)
+    hlo = jax.jit(lambda a, x: ops.rglru_scan(a, x, interpret=False)).lower(
+        *_on(one_chip, (seq, seq))).compile().as_text()
+    calls = _kernel_calls(hlo)
+    assert calls and all(n.startswith("rglru_scan") and "rglru_scan/" in op
+                         for n, op in calls), calls
+
+
 @pytest.mark.parametrize("bucket", [16, 128])
 @pytest.mark.parametrize("model", sorted(SEQ_KERNELS))
 def test_kernel_route_scorer(one_chip, compiled_kernels, model, bucket):

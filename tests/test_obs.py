@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import FLConfig
-from repro.data.synthetic import make_federated
+from repro.data.synthetic import make_federated, make_population
 from repro.obs import TRACER, profile_trace  # noqa: F401 — re-export check
 from repro.obs.stats import StatsRegistry
 from repro.obs.store import ExperimentStore
@@ -76,6 +76,11 @@ def test_jsonl_dump_round_trips(tmp_path):
     assert kinds == {"span", "event"} and len(rows) == 2
     sp = next(r for r in rows if r["type"] == "span")
     assert sp["name"] == "phase" and sp["rep"] == 0 and sp["wall_s"] >= 0
+    # one realtime clock in epoch ns, readable as epoch seconds too
+    assert sp["t0_ns"] <= sp["t1_ns"] and "cpu_s" not in sp
+    assert sp["t0"] == pytest.approx(sp["t0_ns"] * 1e-9)
+    ev = next(r for r in rows if r["type"] == "event")
+    assert sp["t0_ns"] <= ev["t0_ns"] == ev["t1_ns"] <= sp["t1_ns"]
 
 
 # ---------------------------------------------------------------------------
@@ -136,6 +141,14 @@ def test_telemetry_is_bitwise_neutral():
         return [(r.accuracy, r.auc, r.eps_spent,
                  tuple(np.asarray(r.history["acc"]).tolist())) for r in res]
 
+    on, off = _on_and_off(go, lambda: _front_door_spans(
+        "sweep", ("prepare", "execute", "readback", "results"), 2))
+    assert on == off, "telemetry changed the engine's outputs"
+
+
+def _on_and_off(go, check=None):
+    """``go()`` with the global tracer off, then on; ``check`` reads the
+    spans and events of the traced call."""
     was = TRACER.enabled
     TRACER.disable()
     off = go()
@@ -143,14 +156,45 @@ def test_telemetry_is_bitwise_neutral():
     try:
         on = go()
         assert TRACER.find("runner.build"), "instrumented build span missing"
-        assert TRACER.find("sweep.execute"), "execute span missing"
         assert any(e["name"] == "compile.runner_miss" for e in TRACER.events)
+        if check is not None:
+            check()
     finally:
         TRACER.disable()
         TRACER.clear()
         if was:
             TRACER.enable()
-    assert on == off, "telemetry changed the engine's outputs"
+    return on, off
+
+
+def _front_door_spans(door: str, phases, n_lanes: int):
+    """Each phase span of ``door`` recorded once, with its attributes."""
+    for phase in phases:
+        (sp,) = TRACER.find(f"{door}.{phase}")
+        assert sp.attrs["n_lanes"] == n_lanes and sp.t0_ns <= sp.t1_ns
+    (prep,) = TRACER.find(f"{door}.prepare")
+    assert prep.attrs["method"] == "proposed" and prep.attrs["rounds"] == 4
+
+
+def test_population_telemetry_is_bitwise_neutral():
+    pop = make_population(0, n_clients=64, pool_samples=600,
+                          members_per_client=8)
+    fl = FLConfig(n_clients=64, clients_per_round=4, k_max=4, rounds=4,
+                  local_epochs=2, local_batch=32, local_lr=0.1,
+                  dp_enabled=True, dp_mode="clipped", dp_epsilon=1000.0,
+                  dp_clip=1.0, fault_tolerance=True, failure_prob=0.1)
+
+    def go():
+        fl_driver._RUNNER_CACHE.clear()
+        res = fl_driver.run_fl_population(pop, fl, seeds=(0, 1), rounds=4,
+                                          eval_every=2)
+        return [(r.accuracy, r.auc, r.eps_spent, r.sim_time_s,
+                 tuple(np.asarray(r.history["loss"]).tolist()))
+                for row in res for r in row]
+
+    on, off = _on_and_off(go, lambda: _front_door_spans(
+        "population", ("prepare", "execute", "readback", "results"), 2))
+    assert on == off, "telemetry changed the population engine's outputs"
 
 
 # ---------------------------------------------------------------------------
