@@ -515,9 +515,9 @@ RUNNER_STATS = obs_stats.STATS.counters("runner", misses=0, hits=0)
 
 # Device-side federations cached per host FederatedData object, so repeat
 # calls (seed loops, epsilon sweeps) skip the O(n_clients × max_n × d)
-# re-pad + re-upload that stack_federation performs.  Keyed by id() with a
-# weakref guard (FederatedData defines __eq__, so it is unhashable); dead
-# entries are evicted by the weakref callback.
+# re-pad, re-upload and row-table build that stack_federation performs.
+# Keyed by id() with a weakref guard (FederatedData defines __eq__, so it
+# is unhashable); dead entries are evicted by the weakref callback.
 _STACK_CACHE: Dict[int, tuple] = {}
 
 

@@ -12,6 +12,9 @@ path calls it:
   and 128 (``ssm``/``rglru`` → ``rglru_scan``; ``attn`` →
   ``flash_attention`` + ``flash_decode``), and its eval form vmapped over
   sweep lanes, as the training runner evaluates it;
+* the dense plan's batch sampler at the ``mlp_unsw.sweep_dp`` cell's
+  shapes, vmapped over its 8 lanes: each draw must be one whole row of the
+  row-major client table, with no relayout of the table;
 * the sharded runners on all four chips: a 4-lane ``run_fl_sweep`` runner
   on its ``lane`` mesh and a ``run_fl_population`` runner on a (2, 2)
   lane × client mesh, whose kernels must see one lane's operands per
@@ -32,7 +35,8 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 from repro.configs.base import FLConfig
-from repro.data.synthetic import make_federated, make_population
+from repro.data.synthetic import (StackedFederation, make_federated,
+                                  make_population, sample_round_batches)
 from repro.kernels import backend
 from repro.kernels import ops
 from repro.models import sharding as shard_lib
@@ -116,6 +120,34 @@ def test_dp_clip_noise_tree_vmapped_over_clients(one_chip, compiled_kernels):
     count = _kernel_count(privatize, *_on(one_chip, (deltas, raw_keys,
                                                      scalar, scalar)))
     assert count >= 2 * n_leaves    # one sumsq + one scale_noise per leaf
+
+
+def test_batch_sampler_reads_whole_rows(one_chip, no_compile_cache):
+    """The ``mlp_unsw.sweep_dp`` cell's table (40 clients × 29,877 padded
+    rows of 42 features + label, 128 lanes) sampled by 8 lanes.  One local
+    step of 64 draws per client: the layout does not depend on the number
+    of draws, and the compile's time grows with it (about tenfold at the
+    cell's 5 steps)."""
+    n, max_n, d, n_test, lanes = 40, 29_877, 42, 64_418, 8
+    table = (n * max_n, 128)
+    stack = StackedFederation(
+        rows=jax.ShapeDtypeStruct(table, jnp.float32),
+        sizes=jax.ShapeDtypeStruct((n,), jnp.int32),
+        test_x=jax.ShapeDtypeStruct((n_test, d), jnp.float32),
+        test_y=jax.ShapeDtypeStruct((n_test,), jnp.int32))
+    keys = jax.ShapeDtypeStruct((lanes,), jax.random.key(0).dtype)
+    sample = jax.vmap(lambda k, st: sample_round_batches(k, st, 1, 64),
+                      in_axes=(0, None))
+    hlo = jax.jit(sample).lower(*_on(one_chip, (keys, stack))).compile(
+        ).as_text()
+    slices = [tuple(int(v) for v in m.split(","))
+              for m in re.findall(r" gather\(.*slice_sizes=\{([\d,]*)\}", hlo)]
+    assert slices and all(sl[-1] % 128 == 0 for sl in slices), slices
+    shape = r"f32\[%d,%d\]" % table
+    layouts = re.findall(shape + r"\{([\d,]+)", hlo)
+    assert layouts and set(layouts) == {"1,0"}, layouts
+    table_dims = r"[\d,]*\b(?:%d|%d)\b[\d,]*" % (table[0], max_n)
+    assert not re.search(r"= \w+\[%s\]\S* copy\(" % table_dims, hlo)
 
 
 def _kernel_calls(hlo: str):

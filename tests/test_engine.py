@@ -167,9 +167,37 @@ def test_sample_round_batches_respects_client_sizes(fed):
     # rows are drawn from [0, size_i), so labels match the client's own data
     for ci in (0, fed.n_clients - 1):
         rows = np.asarray(b["x"][ci]).reshape(-1, fed.n_features)
-        src = np.asarray(stack.x[ci][: int(stack.sizes[ci])])
+        src = fed.x[ci]
+        assert len(src) == int(stack.sizes[ci])
         for r in rows[:8]:
             assert np.isclose(src, r, atol=1e-6).all(axis=1).any()
+
+
+@pytest.mark.parametrize("dataset,n_samples,width",
+                         [("unsw", 2_000, 128), ("road_raw", 600, 512)])
+def test_sample_round_batches_equal_plain_per_client_draws(dataset,
+                                                           n_samples, width):
+    """The row-table sampler returns, to the bit, what each client's own
+    shard gives at the same indices: the key split into one key per client,
+    ``randint(k, (steps, batch), 0, size)``, then ``fed.x[c][idx]`` and
+    ``fed.y[c][idx]``.  So no pad row (``i >= size``) and no pad lane (the
+    label's, or the zeros after it) reaches a batch."""
+    fed = make_federated(3, dataset, n_samples=n_samples, n_clients=6)
+    stack = stack_federation(fed)
+    assert stack.rows.shape[1] == width
+    steps, batch = 3, 16
+    key = jax.random.key(11)
+    b = jax.jit(lambda k: sample_round_batches(k, stack, steps, batch))(key)
+    assert b["x"].shape == (fed.n_clients, steps, batch, fed.n_features)
+    assert b["x"].dtype == jnp.float32 and b["y"].dtype == jnp.int32
+    for ci, k in enumerate(jax.random.split(key, fed.n_clients)):
+        idx = np.asarray(jax.random.randint(k, (steps, batch), 0,
+                                            len(fed.x[ci])))
+        np.testing.assert_array_equal(
+            np.asarray(b["x"][ci]).view(np.uint32),
+            fed.x[ci][idx].astype(np.float32).view(np.uint32))
+        np.testing.assert_array_equal(np.asarray(b["y"][ci]),
+                                      fed.y[ci][idx].astype(np.int32))
 
 
 # ---------------------------------------------------------------------------
